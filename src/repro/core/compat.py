@@ -1,56 +1,25 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map entry points with this repo's defaults.
 
-The repo targets the modern JAX surface (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types=...``); CI and the dev container pin jax 0.4.37 where those
-live under ``jax.experimental.shard_map`` / have no ``axis_types`` kwarg and
-``jax.sharding.AxisType`` does not exist yet.  Every call site goes through
-this module so the rest of the codebase reads like current JAX and upgrades
-are a one-file change.
+Every call site goes through this module so the defaults are set in one
+place: ``shard_map`` runs with ``check_vma=False`` (jmpi collectives manage
+replication themselves), and meshes get explicit ``AxisType.Auto`` axes
+(GSPMD-style sharding propagation, which every caller here relies on).
 """
 
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` on new JAX; ``jax.experimental.shard_map`` on 0.4.x.
-
-    ``check_vma`` maps to the old API's ``check_rep`` (same meaning: verify
-    per-axis replication claims; our jmpi collectives manage replication
-    manually, so callers pass False).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across the rename: modern JAX spells it
-    ``pltpu.CompilerParams``, 0.4.x ``pltpu.TPUCompilerParams`` (same
-    fields — dimension_semantics, has_side_effects, ...)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """``jax.shard_map`` with replication checks off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types when supported.
-
-    Old JAX (< AxisType) has implicit-auto axes only, which is exactly what
-    every caller here wants, so the kwarg is simply dropped there.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs = {}
-        if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-            kwargs["axis_types"] = (
-                (jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with explicit Auto axis types (JAX's default is
+    Explicit) over the first ``prod(axis_shapes)`` devices."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))

@@ -8,10 +8,15 @@ state stays resident.
 Tiling: grid = (B·KH, n_kv_blocks); one q block holds the G = H/KH query
 heads of one kv group (rows ≤ 8 sublanes for small G — padded by Mosaic),
 K/V blocks are (bk, D) slabs; slot-validity (ring caches, partially filled
-caches) arrives as a precomputed int8 mask — (1, S) shared across the
-batch, or (B, S) per sequence for paged/continuous batching — so the
-kernel needs no scalar prefetch.  VMEM per step ≈ bk·D·2·2B + G·D·4B ≈ 0.27 MiB at bk=1024,
-D=128 — double-buffering the K/V stream dominates, as it should for a
+caches) arrives as a precomputed int32 mask — one row shared across the
+batch, or one row per sequence for paged/continuous batching — so the
+kernel needs no scalar prefetch.  The mask is carried as (rows, 1, S)
+with (1, 1, bk) blocks: the last two block dims then equal the array's
+(1) or are a multiple of 128 (bk), as Mosaic's tiling rule requires, and
+the kernel only ever sees a 2-D (1, bk) row, never a 1-D vector.
+
+VMEM per step ≈ bk·D·2·2B + G·D·4B ≈ 0.27 MiB at bk=1024, D=128 —
+double-buffering the K/V stream dominates, as it should for a
 bandwidth-bound kernel.
 """
 
@@ -23,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.core import compat
 
 NEG_INF = -2.0 ** 30
 MINLANE = 128
@@ -44,8 +48,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref, *,
     v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    valid = mask_ref[0] > 0                               # (bk,)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    valid = mask_ref[0] > 0                               # (1, bk)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[:, 0]
     l_prev = l_ref[:, 0]
@@ -82,15 +86,16 @@ def decode_attention_grouped(q, k, v, valid_mask, *, scale=None, bk=1024,
         pad = nk * bk - s
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        valid_mask = jnp.pad(valid_mask.astype(jnp.int8),
+        valid_mask = jnp.pad(valid_mask.astype(jnp.int32),
                              ((0, 0), (0, pad)) if per_seq else (0, pad))
     qf = q.reshape(b * kh, g, d)
     kf = k.reshape(b * kh, nk * bk, d)
     vf = v.reshape(b * kh, nk * bk, d)
-    maskf = valid_mask.astype(jnp.int8).reshape(b if per_seq else 1, nk * bk)
+    maskf = valid_mask.astype(jnp.int32).reshape(b if per_seq else 1, 1,
+                                                 nk * bk)
     # grid axis 0 is b*kh with kh minor, so sequence = bh // kh
-    mask_idx = ((lambda bh, ki: (bh // kh, ki)) if per_seq
-                else (lambda bh, ki: (0, ki)))
+    mask_idx = ((lambda bh, ki: (bh // kh, 0, ki)) if per_seq
+                else (lambda bh, ki: (0, 0, ki)))
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
 
     kernel = functools.partial(_kernel, scale=sc, n_kv=nk)
@@ -101,7 +106,7 @@ def decode_attention_grouped(q, k, v, valid_mask, *, scale=None, bk=1024,
             pl.BlockSpec((1, g, d), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk), mask_idx),
+            pl.BlockSpec((1, 1, bk), mask_idx),
         ],
         out_specs=pl.BlockSpec((1, g, d), lambda bh, ki: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * kh, g, d), q.dtype),
@@ -110,7 +115,7 @@ def decode_attention_grouped(q, k, v, valid_mask, *, scale=None, bk=1024,
             pltpu.VMEM((g, MINLANE), jnp.float32),
             pltpu.VMEM((g, MINLANE), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, maskf)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.core import compat
 
 NEG_INF = -2.0 ** 30
 MINLANE = 128  # lane-aligned second dim for the m/l scratch
@@ -131,7 +130,7 @@ def flash_attention_bhsd(q, k, v, *, n_kv_heads, window=None, scale=None,
             pltpu.VMEM((bq, MINLANE), jnp.float32),
             pltpu.VMEM((bq, MINLANE), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

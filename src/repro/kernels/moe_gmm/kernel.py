@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.core import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, nvalid_ref, y_ref, *, bc):
@@ -59,7 +59,7 @@ def moe_gmm_ecd(x, w, n_valid=None, *, bc=128, bf=128, interpret=False):
         ],
         out_specs=pl.BlockSpec((1, bc, bf), lambda ei, ci, fi: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, nc * bc, nf * bf), x.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(x, w, nv)

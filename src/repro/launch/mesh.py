@@ -23,11 +23,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(n: int | None = None, axes=("data",)):
-    """Mesh over the locally visible (possibly emulated) devices — used by
-    tests, examples and benchmarks."""
+    """Mesh over the first ``n`` locally visible (possibly emulated)
+    devices — used by tests, examples and benchmarks."""
     n = n if n is not None else len(jax.devices())
-    import numpy as np
-    devs = np.array(jax.devices()[:n])
     if len(axes) == 1:
         shape = (n,)
     else:

@@ -29,8 +29,11 @@ def main():
     import numpy as np
 
     from repro.configs import get_tiny
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import lm as lm_lib
     from repro.serve.engine import ContinuousEngine, Engine, ServeConfig
+
+    use_compile_cache()
 
     cfg = get_tiny(args.arch)
     if cfg.embeds_input or cfg.n_img_tokens:

@@ -44,6 +44,7 @@ def main():
     import repro.core as jmpi
     from repro.configs import get_config, get_tiny
     from repro.configs.base import RunConfig, ShapeCell
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import lm as lm_lib
     from repro.train import checkpoint as ckpt
@@ -52,6 +53,7 @@ def main():
     from repro.train.ft import Watchdog
     from repro.train.trainer import build_jmpi_train_step, build_train_step
 
+    use_compile_cache()
     cfg = get_tiny(args.arch) if args.tiny else get_config(args.arch)
     rc = RunConfig(learning_rate=args.lr, microbatch=args.microbatch,
                    grad_compression_bits=args.grad_compression,

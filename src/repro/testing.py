@@ -4,8 +4,9 @@ Multi-rank behaviour needs emulated devices, but
 ``--xla_force_host_platform_device_count`` is process-global and must never
 leak into the main test process (smoke tests and benches see exactly 1
 device).  Case modules therefore execute in a subprocess with the flag set
-only there, run every ``case_*`` function, and report a per-case PASS/FAIL
-transcript back to the parent.
+only there, pinned to the CPU backend (on a host with a chip the parent
+may hold it), run every ``case_*`` function, and report a per-case
+PASS/FAIL transcript back to the parent.
 
 Speed: the pytest wrappers call :func:`assert_case`, which runs the whole
 case module ONCE per (module, device-count) — the transcript is cached and
@@ -18,8 +19,7 @@ hung case fails loudly instead of eating the blanket subprocess timeout.
 Property-based testing: :func:`property_testing` returns hypothesis's
 ``(given, settings, strategies)`` when the real library is installed and a
 minimal deterministic shim otherwise (seeded rng, ``max_examples`` draws,
-first falsifying example reported) — the container image does not ship
-hypothesis and nothing may be pip-installed there.
+first falsifying example reported).
 """
 
 from __future__ import annotations
@@ -98,8 +98,8 @@ def _run_child(module: str, n_devices: int, only: str | None,
     return subprocess.run(
         [sys.executable, "-c", _RUNNER, module, only or "-",
          str(per_case_timeout)],
-        env=child_env(n_devices), capture_output=True, text=True,
-        timeout=timeout, cwd=_repo_root())
+        env=dict(child_env(n_devices), JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=timeout, cwd=_repo_root())
 
 
 def run_cases(module: str, n_devices: int = 8, only: str | None = None,

@@ -12,8 +12,9 @@ teardown for any job still live when the parent exits, so an interrupted
 pytest run cannot leak processes or shm segments.
 
 The parent stays jax-free: workers are ``python -m repro.transport.worker``
-subprocesses with their own 1-device XLA config (``repro.testing.child_env``
-keeps the import path identical to the parent's).
+subprocesses with their own 1-device XLA config on the CPU backend
+(``repro.testing.child_env`` keeps the import path identical to the
+parent's); they are host-side ranks and never reach for a chip.
 """
 
 from __future__ import annotations
@@ -266,6 +267,8 @@ def launch(nprocs: int, entry: str, *, transport: str = "sock", args=None,
         for rank in range(nprocs):
             env = child_env(1)
             env.update({
+                # ranks are host processes: none may reach for a chip
+                "JAX_PLATFORMS": "cpu",
                 "JMPI_RANK": str(rank),
                 "JMPI_NP": str(nprocs),
                 "JMPI_TRANSPORT": transport,

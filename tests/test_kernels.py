@@ -140,6 +140,24 @@ def test_decode_attention_ring_mask():
                                np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
+def test_decode_attention_per_sequence_mask():
+    """(B, S) mask with its own fill per sequence (paged/continuous
+    batching), S not a multiple of bk so the padded tail is masked too."""
+    b, h, kh, s, d = 3, 4, 2, 200, 32
+    q = randn((b, 1, h, d))
+    k = randn((b, s, kh, d))
+    v = randn((b, s, kh, d))
+    fill = jnp.asarray([1, 77, 200])
+    valid = jnp.arange(s)[None, :] < fill[:, None]
+    out = decode_attention(q, k, v, valid, n_kv_heads=kh, bk=64)
+    g = h // kh
+    ref = decode_attention_ref(q[:, 0].reshape(b, kh, g, d),
+                               jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                               valid)
+    np.testing.assert_allclose(np.asarray(out[:, 0].reshape(b, kh, g, d)),
+                               np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
 # ------------------------------------------------------------------ #
 # mamba2 SSD
 # ------------------------------------------------------------------ #

@@ -1,9 +1,12 @@
 """End-to-end behaviour tests: the trainer learns, the engine serves, the
 dry-run machinery lowers/compiles, and the HLO cost model is calibrated."""
 
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_tiny
 from repro.configs.base import RunConfig, ShapeCell
@@ -105,3 +108,29 @@ def test_hlo_cost_trip_counts():
     r = hlo_cost.analyze(hlo)
     expected = 17 * 2 * 64 * 128 * 128
     assert 0.95 < r["flops"] / expected < 1.1
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_location(env_set, monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper leaves JAX's own
+    setting alone; unset, it points the cache at <repo>/.jax_cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import use_compile_cache
+
+    repo_cache = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = use_compile_cache()
+        if env_set:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(repo_cache)
+            assert jax.config.jax_compilation_cache_dir == str(repo_cache)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()

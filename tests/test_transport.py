@@ -533,6 +533,20 @@ def test_launcher_rejects_bad_arguments():
         launch(2, "not-an-entry")
 
 
+@pytest.mark.parametrize("runner", ["case_subprocess", "transport_job"])
+def test_children_pinned_to_cpu(runner, monkeypatch):
+    """Case children and launcher workers pin JAX_PLATFORMS=cpu themselves,
+    whatever the parent's environment says."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    if runner == "case_subprocess":
+        from repro.testing import run_cases
+        run_cases("tests.cases_platform", 1)
+    else:
+        from repro.transport.testing import assert_case_multiproc
+        assert_case_multiproc("tests.cases_platform",
+                              "case_child_runs_on_cpu", 2, "sock")
+
+
 # ---------------------------------------------------------------------------
 # satellite: plan cache keys carry backend/transport identity
 # ---------------------------------------------------------------------------
