@@ -16,10 +16,12 @@ module instead of once per case.  Every case also runs under a per-case
 SIGALRM timeout (default 120 s, ``REPRO_CASE_TIMEOUT`` to override) so one
 hung case fails loudly instead of eating the blanket subprocess timeout.
 
-Property-based testing: :func:`property_testing` returns hypothesis's
-``(given, settings, strategies)`` when the real library is installed and a
-minimal deterministic shim otherwise (seeded rng, ``max_examples`` draws,
-first falsifying example reported).
+Property-based testing: :func:`property_testing` returns the same draws on
+every run.  Under the multiproc backend (``JMPI_BACKEND=multiproc``) every
+rank runs the case body on its own, so it returns the deterministic shim:
+the same seeded examples on every rank, failing on the first falsifying
+draw with no shrinking.  Elsewhere it returns hypothesis (derandomized, no
+example database) when installed, and the shim otherwise.
 """
 
 from __future__ import annotations
@@ -236,14 +238,25 @@ def _shim_given(**kw_strategies):
 
 
 def property_testing():
-    """(given, settings, strategies) — hypothesis if installed, shim else.
+    """(given, settings, strategies) with one draw sequence on every run.
 
-    The shim draws ``max_examples`` deterministic examples (seeded rng) and
-    reports the first falsifying draw; no shrinking, kwargs-style ``given``
+    Under ``JMPI_BACKEND=multiproc`` — and wherever hypothesis is missing —
+    this is the shim: ``max_examples`` examples from ``default_rng(0)``,
+    identical on every rank, raising on the first falsifying draw with no
+    shrinking (a rank that shrank alone would leave its peers waiting in a
+    collective).  Otherwise it is hypothesis, whose ``settings`` always
+    carry ``derandomize=True`` and ``database=None``: the same examples on
+    every run, and no ``.hypothesis/`` directory.  Kwargs-style ``given``
     only — exactly the surface the case modules use.
     """
+    if os.environ.get("JMPI_BACKEND") == "multiproc":
+        return _shim_given, _shim_settings, _Strategies
     try:
         from hypothesis import given, settings, strategies
-        return given, settings, strategies
     except ImportError:
         return _shim_given, _shim_settings, _Strategies
+
+    def reproducible_settings(*args, **kwargs):
+        return settings(*args, **kwargs, derandomize=True, database=None)
+
+    return given, reproducible_settings, strategies

@@ -48,12 +48,12 @@ static ERR_TRUNCATE) init-time errors rather than steady-state ones.
 
 from __future__ import annotations
 
-import struct
 import time
 
 import numpy as np
 
 from repro.transport import base
+from repro.transport.shm import u64_view
 
 #: Slot payload capacity.  Messages above this are chunk-pipelined
 #: through the slots; at or below it a message is a single zero-staging
@@ -64,8 +64,7 @@ CHUNK_CAP = 256 << 10
 #: with one consumer copy, which is all a single shared segment can use.
 NSLOTS = 2
 
-_U64 = struct.Struct("<Q")
-_GEN_OFF, _SEQ_OFF, _ACK_OFF = 0, 8, 16
+_GEN, _SEQ, _ACK = 0, 1, 2  # u64 counter indices (see shm.u64_view)
 _CTRL_BYTES = 64  # gen + seq + ack, padded out of false-sharing range
 
 
@@ -114,24 +113,22 @@ class ShmChannel:
         self._epoch = endpoint.epoch
         self._recv_buf = (np.empty(shape, dtype)
                           if not sender and self._nchunks > 1 else None)
+        self._ctl = u64_view(buf, 3)      # [gen, seq, ack]
         if sender:
-            _U64.pack_into(buf, _SEQ_OFF, 0)
-            _U64.pack_into(buf, _ACK_OFF, 0)
-            _U64.pack_into(buf, _GEN_OFF, endpoint.epoch + 1)
+            self._ctl[_SEQ] = 0
+            self._ctl[_ACK] = 0
+            self._ctl[_GEN] = endpoint.epoch + 1
         else:
-            self._wait(_GEN_OFF, endpoint.epoch + 1, "generation")
+            self._wait(_GEN, endpoint.epoch + 1, "generation")
 
     # -- counters ------------------------------------------------------------
-    def _u64(self, off: int) -> int:
-        return _U64.unpack_from(self._shm.buf, off)[0]
-
-    def _wait(self, off: int, need: int, what: str) -> None:
-        buf = self._shm.buf
-        if _U64.unpack_from(buf, off)[0] >= need:
+    def _wait(self, idx: int, need: int, what: str) -> None:
+        ctl = self._ctl
+        if ctl[idx] >= need:
             return
         backoff = base.Backoff(spin=300)
         deadline = time.monotonic() + self._ep.timeout
-        while _U64.unpack_from(buf, off)[0] < need:
+        while ctl[idx] < need:
             if backoff.pause() and time.monotonic() > deadline:
                 raise TimeoutError(
                     f"rank {self._ep.rank}: persistent channel to rank "
@@ -148,11 +145,11 @@ class ShmChannel:
         # generation, the receiver waits for it.  Both ends reach their
         # first post-bump use at the same epoch (same SPMD program).
         if self._sender:
-            _U64.pack_into(self._shm.buf, _SEQ_OFF, 0)
-            _U64.pack_into(self._shm.buf, _ACK_OFF, 0)
-            _U64.pack_into(self._shm.buf, _GEN_OFF, ep + 1)
+            self._ctl[_SEQ] = 0
+            self._ctl[_ACK] = 0
+            self._ctl[_GEN] = ep + 1
         else:
-            self._wait(_GEN_OFF, ep + 1, "generation")
+            self._wait(_GEN, ep + 1, "generation")
         self._count = 0
         self._epoch = ep
 
@@ -160,23 +157,22 @@ class ShmChannel:
     def send(self, arr: np.ndarray) -> None:
         """Write one frozen-signature message straight into the slots."""
         self._sync_epoch()
-        buf = self._shm.buf
         if self._nchunks == 1:
             k = self._count
-            self._wait(_ACK_OFF, k + 1 - NSLOTS, "ack")
+            self._wait(_ACK, k + 1 - NSLOTS, "ack")
             np.copyto(self._typed[k % NSLOTS], arr, casting="no")
-            _U64.pack_into(buf, _SEQ_OFF, k + 1)  # publish after payload
+            self._ctl[_SEQ] = k + 1  # publish after payload
             self._count = k + 1
             self._ep._count_chan(self._nbytes, 0)
             return
         src = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
         for c in range(self._nchunks):
             k = self._count
-            self._wait(_ACK_OFF, k + 1 - NSLOTS, "ack")
+            self._wait(_ACK, k + 1 - NSLOTS, "ack")
             lo = c * self._cap
             hi = min(lo + self._cap, self._nbytes)
             self._slots[k % NSLOTS][:hi - lo] = src[lo:hi]
-            _U64.pack_into(buf, _SEQ_OFF, k + 1)
+            self._ctl[_SEQ] = k + 1
             self._count = k + 1
         self._ep._count_chan(self._nbytes, 0)
 
@@ -189,25 +185,24 @@ class ShmChannel:
         self._sync_epoch()
         if self._nchunks == 1:
             k = self._count
-            self._wait(_SEQ_OFF, k + 1, "payload")
+            self._wait(_SEQ, k + 1, "payload")
             self._count = k + 1
             return self._typed[k % NSLOTS]
         dst = self._recv_buf.reshape(-1).view(np.uint8)
-        buf = self._shm.buf
         for c in range(self._nchunks):
             k = self._count
-            self._wait(_SEQ_OFF, k + 1, "payload")
+            self._wait(_SEQ, k + 1, "payload")
             lo = c * self._cap
             hi = min(lo + self._cap, self._nbytes)
             dst[lo:hi] = self._slots[k % NSLOTS][:hi - lo]
-            _U64.pack_into(buf, _ACK_OFF, k + 1)  # slot free for the sender
+            self._ctl[_ACK] = k + 1  # slot free for the sender
             self._count = k + 1
         return self._recv_buf
 
     def release(self) -> None:
         """Done consuming the last :meth:`recv` — ack its slot back."""
         if self._nchunks == 1:
-            _U64.pack_into(self._shm.buf, _ACK_OFF, self._count)
+            self._ctl[_ACK] = self._count
 
     def close(self) -> None:
         # Views alias the mmap; drop ours before closing it.  BufferError
@@ -215,6 +210,7 @@ class ShmChannel:
         # mapping for the interpreter to reclaim, but still unlink the
         # name so the segment cannot leak past the process.
         self._slots, self._typed, self._recv_buf = [], [], None
+        self._ctl.release()
         try:
             self._shm.close()
         except (OSError, BufferError):

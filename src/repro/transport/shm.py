@@ -15,6 +15,13 @@ needs no locks — an 8-byte-aligned u64 store is a single atomic
 instruction on x86-64/aarch64, and the counter update is published only
 *after* the payload bytes it covers are in place.
 
+The counters are read and written only through :func:`u64_view`, whose
+element access is one aligned 8-byte copy.  ``struct.pack_into`` is not
+safe here: it zero-fills the field and then writes it byte by byte, so
+the peer could read ``0`` or a half-written counter, compute a negative
+``head - tail``, and move ``tail`` backwards — the ring then replays
+stale bytes (wrong payloads) or loses frame sync (a hang).
+
 Writers block briefly when the ring is full.  That is deadlock-safe
 here because the endpoint dedicates a reader thread per inbound wire
 that drains unconditionally into per-source queues — the consumer never
@@ -23,7 +30,6 @@ waits on the producer.
 
 from __future__ import annotations
 
-import struct
 import time
 from multiprocessing import shared_memory
 
@@ -34,8 +40,15 @@ from repro.transport import base
 #: bounds the per-pair footprint (n*(n-1) segments per job).
 RING_SIZE = 1 << 20
 
-_U64 = struct.Struct("<Q")
 _HDR_BYTES = 16  # head + tail
+
+
+def u64_view(buf, count: int) -> memoryview:
+    """The first ``count`` u64 counters of a shared segment as a native
+    ``'Q'`` memoryview: each element read or write is a single aligned
+    8-byte copy, so a concurrent peer never sees a torn counter.
+    Release the view before closing the segment."""
+    return buf[:8 * count].cast("Q")
 
 
 def segment_name(session: str, src: int, dst: int) -> str:
@@ -79,18 +92,13 @@ class _Ring:
     def __init__(self, shm: shared_memory.SharedMemory, writer: bool,
                  owner: bool):
         self._shm, self._writer, self._owner = shm, writer, owner
-
-    def _head(self) -> int:
-        return _U64.unpack_from(self._shm.buf, 0)[0]
-
-    def _tail(self) -> int:
-        return _U64.unpack_from(self._shm.buf, 8)[0]
+        self._ctr = u64_view(shm.buf, 2)  # [head, tail]
 
     def write(self, data: bytes, deadline: float) -> None:
         mv, pos = memoryview(data), 0
         backoff = base.Backoff(spin=100)
         while pos < len(mv):
-            head, tail = self._head(), self._tail()
+            head, tail = self._ctr[0], self._ctr[1]
             free = RING_SIZE - (head - tail)
             if free == 0:
                 if backoff.pause() and time.monotonic() > deadline:
@@ -106,7 +114,7 @@ class _Ring:
                 self._shm.buf[_HDR_BYTES:_HDR_BYTES + n - first] = \
                     mv[pos + first:pos + n]
             # Publish AFTER the payload bytes are visible.
-            _U64.pack_into(self._shm.buf, 0, head + n)
+            self._ctr[0] = head + n
             pos += n
 
     def read(self, n: int, deadline: float, stop=None) -> bytearray:
@@ -115,7 +123,7 @@ class _Ring:
         out = bytearray()
         backoff = base.Backoff(spin=100)
         while len(out) < n:
-            head, tail = self._head(), self._tail()
+            head, tail = self._ctr[0], self._ctr[1]
             avail = head - tail
             if avail == 0:
                 if stop is not None and stop():
@@ -132,15 +140,16 @@ class _Ring:
             out += self._shm.buf[_HDR_BYTES + start:_HDR_BYTES + start + first]
             if take > first:
                 out += self._shm.buf[_HDR_BYTES:_HDR_BYTES + take - first]
-            _U64.pack_into(self._shm.buf, 8, tail + take)
+            self._ctr[1] = tail + take
         return out
 
     def close(self) -> None:
+        self._ctr.release()
         try:
             self._shm.close()
             if self._owner:
                 self._shm.unlink()
-        except (OSError, FileNotFoundError):
+        except (OSError, BufferError):
             pass
 
 

@@ -9,6 +9,14 @@ the case modules read ``JMPI_BACKEND``/``JMPI_NP`` at import to size ``N``
 and to route their ``spmd_collective`` helper to :func:`run_collective`,
 so one oracle body is the parity test for both backends.
 
+Every rank runs the case bodies on its own, so every rank must draw the
+same property examples: under ``JMPI_BACKEND=multiproc``
+:func:`repro.testing.property_testing` returns the seeded shim, never
+hypothesis.  A job has a 120 s deadline (:data:`JOB_TIMEOUT`); rank 0
+prints ``START {case}`` before each case, so a job that hangs fails the
+case that started last, with the job's transcripts, and every case after
+it as "not reached".
+
 Worker-side entries (referenced by ``module:function`` name from the
 launcher): :func:`_case_entry` (case runner), :func:`_bench_worker`
 (interactive OMB-style p2p timing loop), :func:`_spin_entry` (barrier
@@ -23,6 +31,10 @@ import json
 import os
 import sys
 import time
+
+#: Deadline of one case-module job, in seconds: more than six times the
+#: slowest module job measured on a CPU host, so only a hang reaches it.
+JOB_TIMEOUT = 120.0
 
 
 def run_collective(fn, shards):
@@ -55,11 +67,14 @@ def _case_entry(comm, args) -> None:
     pre-encoded CTRL frames for the common all-ok vote, pickle only on
     failure) makes a failure on ANY rank visible in rank 0's transcript;
     the epoch bump + barrier between cases guarantees a case that raised
-    mid-exchange cannot leak a stale frame into the next case.
+    mid-exchange cannot leak a stale frame into the next case.  Rank 0's
+    ``START {case}`` line names the case a hung job was in.
     """
     mod = importlib.import_module(args["module"])
     ep = comm.endpoint
     for name in sorted(n for n in dir(mod) if n.startswith("case_")):
+        if comm.rank_id == 0:
+            print(f"START {name}", flush=True)
         err = None
         try:
             getattr(mod, name)()
@@ -78,13 +93,17 @@ def _case_entry(comm, args) -> None:
 
 @functools.lru_cache(maxsize=None)
 def module_results_multiproc(module: str, nprocs: int, transport: str,
-                             timeout: float = 900.0
+                             timeout: float = JOB_TIMEOUT
                              ) -> dict[str, tuple[bool, str]]:
     """Run ``module`` once under a (nprocs, transport) job; {case: (ok, log)}.
 
     Cached per configuration for the life of the test process, mirroring
-    ``repro.testing.module_results`` (including the ``__import__`` /
-    ``__timeout__`` failure sentinels).
+    ``repro.testing.module_results``.  A job that hangs past ``timeout``
+    or loses a worker fails the case that started last with the job's
+    transcripts; the cases that finished keep their verdicts, and the
+    ``__unreached__`` sentinel fails every case that never ran.  When no
+    case is to blame (the module did not import, or the job failed after
+    its last case) the ``__job__`` sentinel fails every case.
     """
     from repro.transport import launcher
 
@@ -92,33 +111,45 @@ def module_results_multiproc(module: str, nprocs: int, transport: str,
                           transport=transport, args={"module": module},
                           timeout=timeout)
     try:
-        transcript = job.wait()
-    except TimeoutError as e:
-        return {"__timeout__": (False, str(e))}
-    except launcher.WorkerFailure as e:
-        return {"__import__": (False, str(e))}
+        transcript, ended = job.wait(), None
+    except (TimeoutError, launcher.WorkerFailure) as e:
+        transcript, ended = job.transcript(0), e
     finally:
         job.close()
+    where = f"multiproc n={nprocs} ({transport})"
     results: dict[str, tuple[bool, str]] = {}
+    started = None
     for line in transcript.splitlines():
-        if line.startswith(("PASS ", "FAIL ")):
+        if line.startswith("START "):
+            started = line.split()[1]
+        elif line.startswith(("PASS ", "FAIL ")):
             name = line.split()[1].rstrip(":")
             results[name] = (line.startswith("PASS "), line)
-    if not results:
-        results["__import__"] = (
+    if ended is not None:
+        if started is None or started in results:
+            return {"__job__": (False, str(ended))}
+        how = "hung" if isinstance(ended, TimeoutError) else "crashed"
+        results[started] = (False, f"case {started} of {module} {how} "
+                                   f"under {where}:\n{ended}")
+        results["__unreached__"] = (
+            False, f"not reached: {started} {how} under {where}")
+    elif not results:
+        results["__job__"] = (
             False, f"case module {module} produced no transcript under "
-                   f"multiproc n={nprocs} ({transport}):\n{transcript}")
+                   f"{where}:\n{transcript}")
     return results
 
 
 def assert_case_multiproc(module: str, case: str, nprocs: int,
-                          transport: str) -> None:
+                          transport: str,
+                          timeout: float = JOB_TIMEOUT) -> None:
     """Assert one case passed under a real-process job (module runs once
-    per (nprocs, transport) configuration, cached)."""
-    results = module_results_multiproc(module, nprocs, transport)
-    for sentinel in ("__import__", "__timeout__"):
-        if sentinel in results:
-            raise AssertionError(results[sentinel][1])
+    per (nprocs, transport, timeout) configuration, cached)."""
+    results = module_results_multiproc(module, nprocs, transport, timeout)
+    if "__job__" in results:
+        raise AssertionError(results["__job__"][1])
+    if case not in results and "__unreached__" in results:
+        raise AssertionError(results["__unreached__"][1])
     assert case in results, (
         f"case {case} not found in {module} under multiproc n={nprocs} "
         f"({transport}); known: {sorted(results)}")

@@ -6,6 +6,13 @@ Each (transport, nprocs) job runs the whole case module once (cached in
 :func:`repro.transport.testing.module_results_multiproc`); the parametrized
 tests then assert per-case slices, mirroring the emulated harness.  CI's
 multiproc smoke lane selects the socket/n=2 slice with ``-k "sock-2"``.
+
+Every rank runs the case bodies on its own, so the property cases draw
+from the seeded shim under multiproc (``repro.testing.property_testing``):
+the same examples on every rank, or the ranks would call collectives with
+different shapes and wait on each other forever.  Each job has a 120 s
+deadline; a job that hangs fails the case it hung in, by name, and the
+cases after it as "not reached".
 """
 
 from __future__ import annotations
@@ -67,3 +74,14 @@ def test_parity_multiproc(case, transport, nprocs):
     if nprocs < 4 and case in CASES_N4_ONLY:
         pytest.skip("case needs a world size >= 4")
     assert_case_multiproc(MODULE, case, nprocs, transport)
+
+
+@pytest.mark.parametrize("nprocs", [2, 4])
+def test_shm_ring_exchange_rounds(nprocs):
+    """Twenty rounds of back-to-back ring ``sendrecv``s over the shm wire
+    (tests/cases_shm_rounds.py) deliver the right bytes and finish well
+    inside a 60 s deadline.  Before the ring read and wrote its counters
+    as whole u64s, most such jobs returned wrong data or hung."""
+    assert_case_multiproc("tests.cases_shm_rounds",
+                          "case_ring_exchange_rounds", nprocs, "shm",
+                          timeout=60.0)

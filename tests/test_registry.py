@@ -224,3 +224,62 @@ def test_property_testing_shim_reports_falsifying_example():
         assert a in (1, 2) and isinstance(b[0], bool)
 
     passing()
+
+
+_PROPERTY_DRAWS = r"""
+import json
+from repro.testing import property_testing
+
+given, settings, st = property_testing()
+draws = []
+
+
+@settings(max_examples=12, deadline=None)
+@given(x=st.integers(0, 2 ** 16), dt=st.sampled_from(["f32", "f64", "i32"]))
+def inner(x, dt):
+    draws.append([x, dt])
+
+
+inner()
+print(json.dumps({"engine": given.__module__, "draws": draws}))
+"""
+
+
+@pytest.mark.parametrize("backend", ["multiproc", None])
+def test_property_testing_draws_repeat_in_fresh_processes(backend, tmp_path):
+    """Two fresh processes draw the same property examples.  Under
+    ``JMPI_BACKEND=multiproc`` (every rank a process of its own) they are
+    the seeded shim's; otherwise hypothesis, derandomized and with no
+    example database (``.hypothesis/examples``)."""
+    import importlib.util
+    import subprocess
+    import sys
+
+    from repro.testing import child_env
+
+    env = child_env(1)
+    env.pop("JMPI_BACKEND", None)
+    if backend is not None:
+        env["JMPI_BACKEND"] = backend
+    runs = [json.loads(subprocess.run(
+        [sys.executable, "-c", _PROPERTY_DRAWS], env=env, cwd=tmp_path,
+        capture_output=True, text=True, timeout=120, check=True).stdout)
+        for _ in range(2)]
+    assert runs[0] == runs[1]
+    assert len(runs[0]["draws"]) == 12
+    assert not (tmp_path / ".hypothesis" / "examples").exists()
+    if backend == "multiproc":
+        from repro.testing import _Strategies, _shim_given, _shim_settings
+
+        st, shim_draws = _Strategies, []
+
+        @_shim_settings(max_examples=12)
+        @_shim_given(x=st.integers(0, 2 ** 16),
+                     dt=st.sampled_from(["f32", "f64", "i32"]))
+        def shim(x, dt):
+            shim_draws.append([x, dt])
+
+        shim()
+        assert runs[0] == {"engine": "repro.testing", "draws": shim_draws}
+    elif importlib.util.find_spec("hypothesis") is not None:
+        assert runs[0]["engine"].startswith("hypothesis")

@@ -111,6 +111,8 @@ def test_shm_ring_wraparound():
     got = reader.read(total, time.monotonic() + 30)
     t.join(timeout=10)
     assert got == payload
+    writer.close()  # the rings hold views of the segment: release first
+    reader.close()
     seg.close()
     seg.unlink()
 
@@ -152,8 +154,8 @@ def _rtt_echo(name_a, name_b, n):
     wb = shm_mod._Ring(b, writer=True, owner=False)
     for _ in range(n):
         wb.write(ra.read(1, d), d)
-    a.close()
-    b.close()
+    ra.close()  # closes the attached segments too (owner=False: no unlink)
+    wb.close()
 
 
 def test_shm_ring_roundtrip_latency_floor():
@@ -170,9 +172,9 @@ def test_shm_ring_roundtrip_latency_floor():
     na, nb = f"jmpi_rtt_a_{os.getpid()}", f"jmpi_rtt_b_{os.getpid()}"
     seg_a = shm_mod._attach(na, create=True, deadline=d)
     seg_b = shm_mod._attach(nb, create=True, deadline=d)
+    wa = shm_mod._Ring(seg_a, writer=True, owner=False)
+    rb = shm_mod._Ring(seg_b, writer=False, owner=False)
     try:
-        wa = shm_mod._Ring(seg_a, writer=True, owner=False)
-        rb = shm_mod._Ring(seg_b, writer=False, owner=False)
         n = 300
         proc = mp.get_context("spawn").Process(
             target=_rtt_echo, args=(na, nb, n), daemon=True)
@@ -191,8 +193,9 @@ def test_shm_ring_roundtrip_latency_floor():
             f"ring RTT median {median:.0f}µs — the adaptive backoff floor "
             f"should be well under the old 2×200µs poll-sleep floor")
     finally:
+        wa.close()
+        rb.close()
         for seg in (seg_a, seg_b):
-            seg.close()
             seg.unlink()
 
 
