@@ -2,7 +2,7 @@
 
 py-pde and PyMPDATA-MPI both reduce their distributed needs to one
 primitive: exchange boundary strips with grid neighbours, then run the local
-stencil.  ``halo_exchange_2d`` implements exactly that — and, since the
+stencil.  ``halo_strips_2d`` implements exactly that — and, since the
 topology subsystem landed, it no longer computes neighbour ranks at all: the
 solver attaches a Cartesian topology once (``world.cart_create((rows,
 cols), periods=(True, True))``) and each decomposed axis is one MPI-3
@@ -10,7 +10,10 @@ cols), periods=(True, True))``) and each decomposed axis is one MPI-3
 strip pair is exactly the collective's slot layout, so what used to be two
 hand-rolled ``sendrecv`` ring permutations per axis is one first-class
 registry collective (``xla_native`` shifts or the p2p-fused ``ring``
-lowering, policy's choice).
+lowering, policy's choice).  ``halo_exchange_2d`` concatenates those
+strips onto the block for the jnp stencils; a fused kernel
+(``pde.mpdata``'s Pallas step) reads the strips themselves and never makes
+the padded copy.
 
 The decomposition layout is the Cartesian grid: decompose along axis 0,
 axis 1, or both, by building the mesh with the matching axis sizes
@@ -39,31 +42,77 @@ import repro.core as jmpi
 from repro.core import datatypes
 
 
-def _exchange_axis(sub: "jmpi.CartComm | None", field, axis: int,
-                   halo: int, algorithm=None):
-    """One decomposed axis as a persistent neighbor_alltoall over the
-    axis' two face datatypes.
+def _faces(blocks, axis: int, halo: int):
+    """The ``lo`` and ``hi`` faces (width ``halo``, perpendicular to
+    ``axis``) of the blocks stacked along the other axis: the faces of the
+    stacked block, without stacking it."""
+    lo, hi = [], []
+    for b in blocks:
+        lo.append(datatypes.face(b.shape, axis, "lo", halo,
+                                 dtype=b.dtype).pack(b))
+        hi.append(datatypes.face(b.shape, axis, "hi", halo,
+                                 dtype=b.dtype).pack(b))
+    return (jnp.concatenate(lo, axis=1 - axis),
+            jnp.concatenate(hi, axis=1 - axis))
+
+
+def _exchange_axis(sub: "jmpi.CartComm | None", lo, hi, algorithm=None):
+    """One decomposed axis as a persistent neighbor_alltoall of its two
+    faces.
 
     Args:
         sub: 1-D periodic CartComm along the axis (None = axis not
             decomposed → periodic local wrap).
-        field: the local block (halo strips are its boundary faces).
-        axis: the decomposed array axis (0 = rows, 1 = cols).
-        halo: face width.
+        lo: the face sent to the −1 neighbour.
+        hi: the face sent to the +1 neighbour.
         algorithm: registry entry to freeze into the plan (None = policy).
     Returns:
         ``(from_minus, from_plus)`` — the halo strips received from the
         −1 / +1 neighbours.
     """
-    lo = datatypes.face(field.shape, axis, "lo", halo, dtype=field.dtype)
-    hi = datatypes.face(field.shape, axis, "hi", halo, dtype=field.dtype)
     if sub is None:
-        return hi.pack(field), lo.pack(field)  # periodic self-wrap
-    send = jnp.stack([lo.pack(field), hi.pack(field)])
+        return hi, lo  # periodic self-wrap
+    send = jnp.stack([lo, hi])
     plan = sub.neighbor_alltoall_init(
         jax.ShapeDtypeStruct(send.shape, send.dtype), algorithm=algorithm)
     _, recv = jmpi.wait(plan.start(send))
     return recv[0], recv[1]
+
+
+def halo_strips_2d(field, cart: "jmpi.CartComm", halo: int = 1, *,
+                   algorithm=None):
+    """The periodic neighbour strips of ``field`` (local block), not
+    concatenated onto it.
+
+    Args:
+        field: the local ``(n, m)`` block.
+        cart: 2-D periodic :class:`~repro.core.topology.CartComm` from
+            ``world.cart_create((rows, cols), periods=(True, True))`` —
+            dim 0 decomposes rows, dim 1 columns; size-1 dims wrap locally.
+        halo: strip width ``h``.
+        algorithm: neighbor-collective registry entry to freeze into the
+            exchange plans (None = the active policy's choice).
+    Returns:
+        ``(top, bottom, left, right)``: the row strips ``(h, m)`` from the
+        −1 / +1 row neighbours, then the column strips ``(n + 2h, h)`` from
+        the −1 / +1 column neighbours, corners included: the column phase
+        sends the faces of the row-padded block.
+    Raises:
+        ValueError: ``cart`` is not 2-dimensional.
+    """
+    if cart.ndims != 2:
+        raise ValueError(f"a 2-D halo exchange needs a 2-D CartComm, got "
+                         f"{cart.ndims}-D dims={cart.dims}")
+    h = halo
+    sub_r = cart.cart_sub((True, False)) if cart.dims[0] > 1 else None
+    sub_c = cart.cart_sub((False, True)) if cart.dims[1] > 1 else None
+
+    # --- axis 0 (rows): 'lo' face to the -1 neighbour, 'hi' to the +1 ----
+    top, bottom = _exchange_axis(sub_r, *_faces([field], 0, h), algorithm)
+    # --- axis 1 (cols): faces of the row-padded block, so corners resolve -
+    left, right = _exchange_axis(
+        sub_c, *_faces([top, field, bottom], 1, h), algorithm)
+    return top, bottom, left, right
 
 
 def halo_exchange_2d(field, cart: "jmpi.CartComm", halo: int = 1, *,
@@ -72,32 +121,20 @@ def halo_exchange_2d(field, cart: "jmpi.CartComm", halo: int = 1, *,
 
     Args:
         field: the local ``(n, m)`` block.
-        cart: 2-D periodic :class:`~repro.core.topology.CartComm` from
-            ``world.cart_create((rows, cols), periods=(True, True))`` —
-            dim 0 decomposes rows, dim 1 columns; size-1 dims wrap locally.
+        cart: 2-D periodic CartComm (see :func:`halo_strips_2d`).
         halo: strip width.
         algorithm: neighbor-collective registry entry to freeze into the
             exchange plans (None = the active policy's choice).
     Returns:
-        The ``(n + 2·halo, m + 2·halo)`` padded block; the column phase
-        includes the fresh halo rows so corners resolve.
+        The ``(n + 2·halo, m + 2·halo)`` padded block: the strips of
+        :func:`halo_strips_2d` concatenated onto it.
     Raises:
         ValueError: ``cart`` is not 2-dimensional.
     """
-    if cart.ndims != 2:
-        raise ValueError(f"halo_exchange_2d needs a 2-D CartComm, got "
-                         f"{cart.ndims}-D dims={cart.dims}")
-    h = halo
-    sub_r = cart.cart_sub((True, False)) if cart.dims[0] > 1 else None
-    sub_c = cart.cart_sub((False, True)) if cart.dims[1] > 1 else None
-
-    # --- axis 0 (rows): 'lo' face to the -1 neighbour, 'hi' to the +1 ----
-    top_halo, bot_halo = _exchange_axis(sub_r, field, 0, h, algorithm)
-    field = jnp.concatenate([top_halo, field, bot_halo], axis=0)
-
-    # --- axis 1 (cols): faces of the row-padded block, so corners resolve -
-    left_halo, right_halo = _exchange_axis(sub_c, field, 1, h, algorithm)
-    return jnp.concatenate([left_halo, field, right_halo], axis=1)
+    top, bottom, left, right = halo_strips_2d(field, cart, halo,
+                                              algorithm=algorithm)
+    rows = jnp.concatenate([top, field, bottom], axis=0)
+    return jnp.concatenate([left, rows, right], axis=1)
 
 
 def global_sum(field, *comms: "jmpi.Communicator | None"):

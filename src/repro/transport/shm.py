@@ -70,7 +70,9 @@ def _attach(name: str, create: bool, deadline: float) -> shared_memory.SharedMem
         try:
             shm = shared_memory.SharedMemory(name=name)
             break
-        except FileNotFoundError:
+        except (FileNotFoundError, ValueError):
+            # ValueError: the creator has opened the segment but not yet
+            # sized it, and an empty file cannot be mapped
             if time.monotonic() > deadline:
                 raise TimeoutError(f"shm rendezvous: segment {name} never "
                                    "appeared (creator died?)")

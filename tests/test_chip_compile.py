@@ -72,7 +72,9 @@ def test_decode_kernel_compiles_at_yi6b_widths(one_chip, mask_form):
 def test_mpdata_block_compiles(topo, n, grid):
     """The solver's whole time block (10 steps) at chip_smoke's fields: one
     chip at 8192², and 16384² decomposed over the 2x2 mesh, where the halo
-    exchange must lower to collective-permutes."""
+    exchange must lower to collective-permutes.  Each step is the fused
+    Pallas kernel, so the block holds no field-sized temporary beyond the
+    loop's second buffer."""
     devs = np.array(topo.devices[:grid[0] * grid[1]]).reshape(grid)
     mesh = Mesh(devs, ("x", "y"), axis_types=(AxisType.Auto,) * 2)
     solve = mpdata.make_solver(mesh, inner_steps=10)
@@ -80,6 +82,10 @@ def test_mpdata_block_compiles(topo, n, grid):
                                sharding=NamedSharding(mesh, P("x", "y")))
     compiled = jax.jit(lambda p: solve(p)).lower(psi).compile()
     assert _bytes_per_chip(compiled) < HBM_BYTES
+    block_bytes = (n // grid[0]) * (n // grid[1]) * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= block_bytes + (64 << 20), (temp, block_bytes)
+    assert "tpu_custom_call" in compiled.as_text()
     permutes = compiled.as_text().count("collective-permute-start")
     if grid == (1, 1):
         assert permutes == 0

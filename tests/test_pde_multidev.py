@@ -13,6 +13,9 @@ CASES = [
     "case_mpdata_conservation_and_positivity",
     "case_cahn_hilliard_conserves_mass_when_k0",
     "case_cahn_hilliard_diagnostics_mass",
+    "case_halo_strips_match_padded_block",
+    "case_mpdata_fused_decomposed_matches_oracle",
+    "case_mpdata_fused_conservation_and_positivity",
 ]
 
 
